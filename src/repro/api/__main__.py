@@ -13,6 +13,7 @@ import argparse
 import json
 
 from repro.api import Experiment, ExperimentSpec
+from repro.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -23,6 +24,7 @@ def main(argv=None) -> int:
                    help="serialize->reload->rerun and compare summaries")
     p.add_argument("--quiet", action="store_true")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     spec = ExperimentSpec.load(args.spec)
     on_round = None

@@ -15,6 +15,11 @@ caller in completion order for progress display.
 spec) runs serially in-process — no pool, no pickling — which is also the
 fallback when a pool cannot be spawned (restricted environments).
 
+Real-learner specs (``learner="real"``) always run in the calling
+process, after the pooled surrogate specs: that process holds the
+accelerator, and a chip belongs to one process at a time, so a worker
+that needed it would fail or hang.
+
 Lane-batched mode (``vectorize=True``)
 --------------------------------------
 
@@ -516,6 +521,9 @@ def sweep(specs: Sequence[ExperimentSpec], workers: Optional[int] = None,
                             _n_workers(len(specs), workers))
     else:
         jobs = [("spec", [i]) for i in range(len(specs))]
+    # real-learner specs are never packed, so each is a one-spec job
+    pooled = [j for j in jobs if specs[j[1][0]].learner != "real"]
+    in_parent = [j for j in jobs if specs[j[1][0]].learner == "real"]
     results: List[Optional[Result]] = [None] * len(specs)
 
     def deliver(idxs: List[int], rs: List[Result]) -> None:
@@ -527,7 +535,7 @@ def sweep(specs: Sequence[ExperimentSpec], workers: Optional[int] = None,
     if fault_tolerant:
         st = _FTState(len(specs), deliver, retry_limit, retry_backoff_s,
                       on_failure)
-        ft_jobs = [_FTJob(kind, list(idxs)) for kind, idxs in jobs]
+        ft_jobs = [_FTJob(kind, list(idxs)) for kind, idxs in pooled]
         n = _n_workers(len(ft_jobs), workers)
         try:
             _sweep_ft_pool(ft_jobs, specs, n, st, timeout_s)
@@ -543,13 +551,15 @@ def sweep(specs: Sequence[ExperimentSpec], workers: Optional[int] = None,
                 f"worker-death detection are disabled, retries still "
                 f"apply", RuntimeWarning, stacklevel=2)
             _sweep_ft_serial(remaining, specs, st)
+        _sweep_ft_serial([_FTJob(kind, list(idxs))
+                          for kind, idxs in in_parent], specs, st)
         report = SweepReport(st.reports)
         return (results, report) if return_report else results
 
-    n = _n_workers(len(jobs), workers)
-    if n > 1 and len(jobs) > 1:
+    n = _n_workers(len(pooled), workers)
+    if n > 1 and len(pooled) > 1:
         try:
-            _sweep_pool(jobs, specs, n, deliver)
+            _sweep_pool(pooled, specs, n, deliver)
         except _TaskFailed as tf:
             raise tf.error                # an experiment itself failed
         except _POOL_ERRORS as e:

@@ -20,11 +20,11 @@ import numpy as np
 from repro.kernels.int8_quant import ops as q8
 
 
-def compress_roundtrip(delta: Dict[str, jnp.ndarray], block: int = 256,
-                       use_pallas: bool = False) -> Dict[str, jnp.ndarray]:
-    """Simulate the int8 uplink: quantize + dequantize each leaf."""
-    return {k: q8.quant_dequant(v, block=block, use_pallas=use_pallas)
-            for k, v in delta.items()}
+def compress_roundtrip(delta: Dict[str, jnp.ndarray], block: int = 256
+                       ) -> Dict[str, jnp.ndarray]:
+    """Simulate the int8 uplink: quantize + dequantize each leaf (the
+    Pallas kernel on a TPU, the jnp reference elsewhere)."""
+    return {k: q8.quant_dequant(v, block=block) for k, v in delta.items()}
 
 
 @jax.jit

@@ -41,6 +41,8 @@ class RealLearner:
                                     eps=fed.adam_eps)
         self.opt_state = self.opt.init(self.params)
         self._client_update = make_client_update(self.model.loss, fed.client_lr)
+        self._vmapped_update = jax.jit(jax.vmap(
+            self._client_update, in_axes=(None, 0, 0)))
         self.version = 0
         self._history: List[Tuple[int, Dict[str, np.ndarray]]] = []
         self._push_history()
@@ -85,11 +87,6 @@ class RealLearner:
         cohort = {k: np.stack([s[k] for s in stacked_all])
                   for k in stacked_all[0]}
         cmask = np.stack(masks)
-        if not hasattr(self, "_vmapped_update"):
-            self._vmapped_update = jax.jit(jax.vmap(
-                self._client_update._fun
-                if hasattr(self._client_update, "_fun") else
-                self._client_update, in_axes=(None, 0, 0)))
         deltas, _ = self._vmapped_update(base, cohort, cmask)
         if self.fed.compression == "int8":
             deltas = aggregation.compress_roundtrip(

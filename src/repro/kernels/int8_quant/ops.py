@@ -1,5 +1,6 @@
-"""Public int8-codec ops: jit'd wrappers that dispatch Pallas on TPU and the
-pure-jnp oracle elsewhere (CPU dry-run / tests use interpret=True Pallas)."""
+"""Public int8-codec ops: the Pallas kernels on a TPU, the pure-jnp oracle
+elsewhere. ``interpret=True`` runs the Pallas kernels in interpret mode on
+any backend (kernel tests off the chip); nothing else selects it."""
 from __future__ import annotations
 
 from typing import Tuple
@@ -11,16 +12,14 @@ from repro.kernels.int8_quant import kernel as K
 from repro.kernels.int8_quant import ref as R
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _pallas(interpret: bool) -> bool:
+    return interpret or jax.default_backend() == "tpu"
 
 
-def quantize(x: jnp.ndarray, block: int = 256, use_pallas: bool | None = None
+def quantize(x: jnp.ndarray, block: int = 256, *, interpret: bool = False
              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas:
-        return K.quantize_pallas(x, block=block, interpret=not _on_tpu())
+    if _pallas(interpret):
+        return K.quantize_pallas(x, block=block, interpret=interpret)
     return R.quantize_ref(x, block)
 
 
@@ -28,28 +27,24 @@ def dequantize(q, s, shape, block: int = 256):
     return R.dequantize_ref(q, s, shape, block)
 
 
-def quant_dequant(x: jnp.ndarray, block: int = 256,
-                  use_pallas: bool | None = None) -> jnp.ndarray:
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas:
-        q, s = K.quantize_pallas(x, block=block, interpret=not _on_tpu())
+def quant_dequant(x: jnp.ndarray, block: int = 256, *,
+                  interpret: bool = False) -> jnp.ndarray:
+    if _pallas(interpret):
+        q, s = K.quantize_pallas(x, block=block, interpret=interpret)
         return R.dequantize_ref(q, s, x.shape, block).astype(x.dtype)
     return R.quant_dequant_ref(x, block)
 
 
 def dequant_accumulate(acc: jnp.ndarray, q: jnp.ndarray, s: jnp.ndarray,
-                       weight, block: int = 256,
-                       use_pallas: bool | None = None) -> jnp.ndarray:
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas:
+                       weight, block: int = 256, *,
+                       interpret: bool = False) -> jnp.ndarray:
+    if _pallas(interpret):
         nb = q.shape[0]
         flat = acc.astype(jnp.float32).reshape(-1)
         pad = nb * block - flat.shape[0]
         acc2d = jnp.pad(flat, (0, pad)).reshape(nb, block)
         out = K.dequant_accumulate_pallas(acc2d, q, s, weight,
-                                          interpret=not _on_tpu())
+                                          interpret=interpret)
         return out.reshape(-1)[: flat.shape[0]].reshape(acc.shape).astype(acc.dtype)
     return R.dequant_accumulate_ref(acc, q, s, weight, block)
 

@@ -18,6 +18,7 @@ import argparse
 
 from repro.api import Experiment, ExperimentSpec, ModelRef
 from repro.checkpoint import save_checkpoint
+from repro.compile_cache import enable_compile_cache
 from repro.configs import FederatedConfig, RunConfig, get_config
 
 
@@ -51,7 +52,7 @@ def spec_from_args(args) -> ExperimentSpec:
         seq_len=args.seq_len)
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="paper-charlm")
     p.add_argument("--mode", default="sync", choices=("sync", "async"))
@@ -84,7 +85,12 @@ def main(argv=None):
                    help="resume from an engine snapshot (the spec "
                         "travels inside it; other args are ignored)")
     p.add_argument("--json", default="")
-    args = p.parse_args(argv)
+    return p
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     if args.resume:
         t0 = time.time()

@@ -5,12 +5,6 @@ import os
 # keep test threads polite on shared CI boxes
 os.environ.setdefault("XLA_FLAGS", "")
 
-try:
-    import hypothesis  # noqa: F401  — real package, if the image has it
-except ImportError:  # fall back to the deterministic stub in this dir
-    import _hypothesis_stub
-    _hypothesis_stub.install()
-
 import jax
 import numpy as np
 import pytest

@@ -33,7 +33,7 @@ def test_pallas_dequant_accumulate(shape):
     x = jax.random.normal(jax.random.PRNGKey(3), shape)
     acc = jax.random.normal(jax.random.PRNGKey(4), shape)
     q, s = quantize_pallas(x, block=128, interpret=True)
-    got = ops.dequant_accumulate(acc, q, s, 0.25, block=128, use_pallas=True)
+    got = ops.dequant_accumulate(acc, q, s, 0.25, block=128, interpret=True)
     want = ref.dequant_accumulate_ref(
         acc, *ref.quantize_ref(x, 128), 0.25, block=128)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)

@@ -8,6 +8,8 @@ drives the property tests and the fault-tolerant sweep suite."""
 import json
 import math
 import os
+import pathlib
+import tempfile
 
 import numpy as np
 import pytest
@@ -75,8 +77,8 @@ def _crash_and_resume(monkeypatch, tmp_path, spec, crash_at, every=4):
 @settings(max_examples=3, deadline=None)
 @given(st.integers(min_value=6, max_value=18),
        st.integers(min_value=0, max_value=10_000))
-def test_killed_and_resumed_run_is_bit_exact(mode, telemetry, monkeypatch,
-                                             tmp_path, crash_at, seed0):
+def test_killed_and_resumed_run_is_bit_exact(mode, telemetry, crash_at,
+                                             seed0):
     """The property the whole subsystem exists for: kill at a random
     round, resume from the last checkpoint, get the identical experiment
     — summaries `==` and every session column array_equal (dtype
@@ -86,9 +88,12 @@ def test_killed_and_resumed_run_is_bit_exact(mode, telemetry, monkeypatch,
                  env_idx=int(rng.integers(len(_ENVS))), telemetry=telemetry)
     base = Experiment(spec).run()
     assert base.rounds == spec.run.max_rounds     # crash round was live
-    _, res = _crash_and_resume(monkeypatch, tmp_path, spec, crash_at)
-    assert res.summary() == base.summary()
-    _assert_same_columns(res.log.columns(), base.log.columns())
+    # fresh per example: hypothesis runs many examples per test call
+    with pytest.MonkeyPatch.context() as mp, \
+            tempfile.TemporaryDirectory() as tmp:
+        _, res = _crash_and_resume(mp, pathlib.Path(tmp), spec, crash_at)
+        assert res.summary() == base.summary()
+        _assert_same_columns(res.log.columns(), base.log.columns())
 
 
 def test_resume_keeps_checkpointing_to_the_same_file(monkeypatch,

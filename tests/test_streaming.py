@@ -116,8 +116,7 @@ def test_streaming_matches_full_property(seed0):
 @settings(max_examples=4, deadline=None)
 @given(st.integers(min_value=0, max_value=10_000),
        st.integers(min_value=7, max_value=200))
-def test_reservoir_invariant_to_chunking_and_lanes(monkeypatch, seed0,
-                                                   chunk):
+def test_reservoir_invariant_to_chunking_and_lanes(seed0, chunk):
     """The retained session set is a pure function of (seed, global
     index): identical across dispatch chunk sizes and serial vs
     lane_loop, for every mode — and it IS the bottom-k of
@@ -132,9 +131,9 @@ def test_reservoir_invariant_to_chunking_and_lanes(monkeypatch, seed0,
                   telemetry="streaming", sample=int(rng.integers(5, 60)))
         spec = _spec(**kw)
         serial = Experiment(spec).run()
-        monkeypatch.setattr(rt, "_DISPATCH_CHUNK", chunk)
-        chunked = Experiment(spec).run()
-        monkeypatch.setattr(rt, "_DISPATCH_CHUNK", 1 << 17)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(rt, "_DISPATCH_CHUNK", chunk)
+            chunked = Experiment(spec).run()
         lane = sweep([spec, _spec(mode=mode, conc=9, goal_frac=1.0,
                                   seed=3, max_rounds=5,
                                   telemetry="streaming")],

@@ -200,3 +200,31 @@ def test_legacy_sweep_semantics_unchanged():
     results = sweep(specs, workers=1)
     assert [r.summary() for r in results] \
         == [Experiment(s).run().summary() for s in specs]
+
+
+# ------------------------------------------------- one process per chip
+@pytest.mark.parametrize("fault_tolerant", [False, True])
+def test_real_learner_specs_run_in_the_parent(monkeypatch, fault_tolerant):
+    """The parent holds the accelerator: real-learner specs never reach a
+    worker process, while surrogate specs keep their pool."""
+    import os
+    monkeypatch.setattr(sweep_mod, "run_spec", lambda spec: os.getpid())
+    pooled = []
+    for name in ("_sweep_pool", "_sweep_ft_pool"):
+        real_fn = getattr(sweep_mod, name)
+
+        def spy(jobs, *a, _fn=real_fn, **kw):
+            pooled.extend(i for job in jobs
+                          for i in (job[1] if isinstance(job, tuple)
+                                    else job.idxs))
+            return _fn(jobs, *a, **kw)
+        monkeypatch.setattr(sweep_mod, name, spy)
+    specs = [_spec(s).replace(learner="real" if s % 2 else "surrogate")
+             for s in range(6)]
+    kw = {"return_report": True} if fault_tolerant else {}
+    out = sweep(specs, workers=4, **kw)
+    pids = out[0] if fault_tolerant else out
+    real = [i for i, s in enumerate(specs) if s.learner == "real"]
+    assert not set(pooled) & set(real)
+    assert all(pids[i] == os.getpid() for i in real)
+    assert all(pids[i] != os.getpid() for i in (0, 2, 4))   # pooled
