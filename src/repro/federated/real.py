@@ -5,6 +5,12 @@ once (ragged client datasets are padded into a fixed scan length), and —
 for FedBuff — keeps a ring of recent param versions so stale clients
 really do train against the model they were sent (true staleness, not an
 approximation). Deltas optionally round-trip the int8 wire codec.
+
+Every host<->device copy sits in a span (``repro.spans``; off unless
+enabled), apart from the waits on the device, so a trace can tell the
+copies from the device work. The spans change nothing the program does:
+they wrap the calls it makes untraced, and a copy to the device that has
+not landed when its call returns falls in the next span that waits.
 """
 from __future__ import annotations
 
@@ -14,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.configs.base import FederatedConfig, ModelConfig, RunConfig
 from repro.data.synthetic import FederatedDataset
 from repro.federated import aggregation
@@ -57,7 +64,10 @@ class RealLearner:
 
     # -------------------------------------------------------------- history
     def _push_history(self):
-        self._history.append((self.version, jax.device_get(self.params)))
+        with spans.span("server.history_to_host", update=self.version,
+                        copies=self.params):
+            host = jax.device_get(self.params)
+        self._history.append((self.version, host))
         cap = max(2, self.fed.staleness_cap)
         if len(self._history) > cap:
             self._history.pop(0)
@@ -68,66 +78,99 @@ class RealLearner:
                 return p
         return self._history[0][1]
 
+    # -------------------------------------------------------------- transfers
+    def _train(self, update_fn, version: Optional[int], data, mask,
+               update: int):
+        """Calls a client program on host batches and returns its delta(s)
+        on the host. The call copies its host arguments to the device (the
+        batches and mask, and a stale base from the host ring) and
+        dispatches the program, so one span holds the copies and the
+        dispatch."""
+        stale = version is not None and version != self.version
+        base = self.params_at(version) if stale else self.params
+        with spans.span("client.to_device", update=update,
+                        copies=(base, data, mask) if stale else (data, mask)):
+            deltas, _ = update_fn(base, data, mask)
+        if self.fed.compression == "int8":
+            deltas = aggregation.compress_roundtrip(
+                deltas, block=self.fed.quant_block)
+        # the copies to the host start as the program ends, as a bare
+        # device_get starts them, so the wait holds the device time alone
+        for x in jax.tree_util.tree_leaves(deltas):
+            x.copy_to_host_async()
+        with spans.span("client.wait", update=update):
+            jax.block_until_ready(deltas)
+        with spans.span("client.to_host", update=update, copies=deltas):
+            return jax.device_get(deltas)
+
     # -------------------------------------------------------------- learner
     def client_deltas(self, client_ids, version: Optional[int] = None):
         """Vmapped cohort update (true cross-device simulation): all clients
         train in parallel from the same server params — one compiled call
         per round instead of len(cohort) sequential ones."""
-        base = self.params if version is None or version == self.version \
-            else self.params_at(version)
-        stacked_all, masks, n_ex = [], [], []
-        for cid in client_ids:
-            batches = self.dataset.client_batches(
-                cid, self.fed.client_batch_size, self.fed.local_epochs)
-            st, m = stack_batches(batches, self.max_steps)
-            stacked_all.append(st)
-            masks.append(m)
-            n_ex.append(min(len(batches), self.max_steps)
-                        * self.fed.client_batch_size)
-        cohort = {k: np.stack([s[k] for s in stacked_all])
-                  for k in stacked_all[0]}
-        cmask = np.stack(masks)
-        deltas, _ = self._vmapped_update(base, cohort, cmask)
-        if self.fed.compression == "int8":
-            deltas = aggregation.compress_roundtrip(
-                deltas, block=self.fed.quant_block)
-        out = jax.device_get(deltas)
+        feeds = self.version + 1
+        batches = [self.dataset.client_batches(
+            cid, self.fed.client_batch_size, self.fed.local_epochs)
+            for cid in client_ids]
+        with spans.span("client.pack", update=feeds):
+            packed = [stack_batches(b, self.max_steps) for b in batches]
+            cohort = {k: np.stack([st[k] for st, _ in packed])
+                      for k in packed[0][0]}
+            cmask = np.stack([m for _, m in packed])
+        _count_rows(cohort["mask"])
+        n_ex = [float(min(len(b), self.max_steps) * self.fed.client_batch_size)
+                for b in batches]
+        out = self._train(self._vmapped_update, version, cohort, cmask, feeds)
         return [{k: v[i] for k, v in out.items()}
-                for i in range(len(client_ids))], [float(n) for n in n_ex]
+                for i in range(len(client_ids))], n_ex
 
     def client_delta(self, client_id: int, version: Optional[int] = None):
         """Run real local training; returns (delta dict, example weight)."""
-        base = self.params if version is None or version == self.version \
-            else self.params_at(version)
+        feeds = self.version + 1
         batches = self.dataset.client_batches(
             client_id, self.fed.client_batch_size, self.fed.local_epochs)
-        stacked, mask = stack_batches(batches, self.max_steps)
-        delta, _ = self._client_update(base, stacked, mask)
-        if self.fed.compression == "int8":
-            delta = aggregation.compress_roundtrip(delta,
-                                                   block=self.fed.quant_block)
+        with spans.span("client.pack", update=feeds):
+            stacked, mask = stack_batches(batches, self.max_steps)
+        _count_rows(stacked["mask"])
         n_ex = min(len(batches), self.max_steps) * self.fed.client_batch_size
-        return jax.device_get(delta), float(n_ex)
+        return (self._train(self._client_update, version, stacked, mask,
+                            feeds), float(n_ex))
 
     def apply(self, deltas: List[Dict[str, np.ndarray]], weights: List[float],
               *, n_contributors: int = 0, mean_staleness: float = 0.0,
               staleness: Optional[List[int]] = None) -> None:
         assert deltas, "apply() with empty buffer"
+        feeds = self.version + 1
         w = np.asarray(weights, np.float32)
         if staleness is not None:  # FedBuff staleness scaling
             w = w * aggregation.fedbuff_weights(staleness,
                                                 self.fed.staleness_exponent)
-        stacked = {k: jnp.stack([d[k] for d in deltas]) for k in deltas[0]}
-        mean_delta = aggregation.weighted_mean_deltas(stacked, jnp.asarray(w))
-        self.params, self.opt_state = self._server_step(
-            self.params, self.opt_state, mean_delta)
+        with spans.span("server.to_device", update=feeds, copies=deltas):
+            stacked = {k: jnp.stack([d[k] for d in deltas])
+                       for k in deltas[0]}
+        with spans.span("server.update", update=feeds):
+            mean_delta = aggregation.weighted_mean_deltas(stacked,
+                                                          jnp.asarray(w))
+            self.params, self.opt_state = self._server_step(
+                self.params, self.opt_state, mean_delta)
         self.version += 1
         self._push_history()
 
     def eval_perplexity(self) -> float:
-        if self._eval_batch is None:
-            self._eval_batch = self.dataset.eval_batch(
-                self.run.eval_clients, batch_size=32)
-            self._eval_fn = jax.jit(lambda p, b: self.model.loss(p, b)[0])
-        loss = self._eval_fn(self.params, self._eval_batch)
-        return float(np.exp(np.clip(np.asarray(loss), 0, 20)))
+        with spans.span("server.eval", update=self.version):
+            if self._eval_batch is None:
+                self._eval_batch = self.dataset.eval_batch(
+                    self.run.eval_clients, batch_size=32)
+                self._eval_fn = jax.jit(
+                    lambda p, b: self.model.loss(p, b)[0])
+            loss = self._eval_fn(self.params, self._eval_batch)
+            return float(np.exp(np.clip(np.asarray(loss), 0, 20)))
+
+
+def _count_rows(mask: np.ndarray) -> None:
+    """Rows the client update computes (steps x batch per client) and the
+    rows among them that hold data: a data row's mask is 1 at its first
+    position, a padding row's is 0."""
+    if spans.enabled():
+        spans.count("client.rows_real", int(mask[..., 0].sum()))
+        spans.count("client.rows_computed", mask[..., 0].size)
