@@ -22,11 +22,12 @@ as JSON lines. An operator turns the spans on around a run of their own::
     totals = repro.spans.snapshot()
     repro.spans.dump(f"{trace_dir}/program_spans.jsonl")
 
-Span names are fixed (``SPANS``). A span whose name ends in
-``to_device`` or ``to_host`` holds a call that copies between host and
-device, and counts the bytes it copies. A copy to the host returns with
-the bytes there; a copy to the device may return before they land, and
-the rest of it then falls in the next span that waits on the device.
+Span and counter names are fixed (``SPANS``, ``COUNTERS``). A span whose
+name ends in ``to_device`` or ``to_host`` holds a call that copies between
+host and device, and counts the bytes it copies. A copy to the host
+returns with the bytes there; a copy to the device may return before they
+land, and the rest of it then falls in the next span that waits on the
+device.
 """
 from __future__ import annotations
 
@@ -39,6 +40,11 @@ import jax
 SPANS = ("client.pack", "client.to_device", "client.wait", "client.to_host",
          "server.to_device", "server.update", "server.history_to_host",
          "server.eval")
+# client.rows_*: rows of the client update that hold data, and all it
+# computes; server.deltas_*: client deltas the server step took from the
+# device copies the client programs made, and those it uploaded
+COUNTERS = ("client.rows_real", "client.rows_computed",
+            "server.deltas_resident", "server.deltas_uploaded")
 
 _on = False
 _records: List[Dict[str, Any]] = []

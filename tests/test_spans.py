@@ -146,6 +146,10 @@ def test_sync_transfer_bytes_are_the_shapes_count():
     for name in ("client.pack", "client.wait", "server.update",
                  "server.eval"):
         assert got[name] == (1, 0)
+    # the learner keeps no device copy until rows come back: all uploaded
+    counters = spans.snapshot()["counters"]
+    assert (counters["server.deltas_resident"],
+            counters["server.deltas_uploaded"]) == (0, k)
 
 
 def test_a_stale_base_is_counted_once_per_stale_client_only():
@@ -163,7 +167,9 @@ def test_a_stale_base_is_counted_once_per_stale_client_only():
     got = spans.snapshot()["spans"]
     assert (got["client.to_host"]["calls"],
             got["client.to_host"]["bytes"]) == (3, 3 * pb)
-    assert got["server.to_device"]["bytes"] == 3 * pb
+    # the first update's row goes up again, since no device copy is kept
+    # before a row comes back; the second update's two rows stay put
+    assert got["server.to_device"]["bytes"] == pb
     assert got["server.history_to_host"]["bytes"] == 2 * pb
 
 
