@@ -70,6 +70,12 @@ class ModelConfig:
     dtype: str = "bfloat16"
     # rope
     rope_theta: float = 10000.0
+    # init and norm numerics as a published config states them: None draws
+    # each family's own fixed scales; a float draws every normally
+    # initialised weight from N(0, initializer_range**2) (HF Llama's
+    # `initializer_range`)
+    initializer_range: Optional[float] = None
+    rms_norm_eps: float = 1e-6
 
     def __post_init__(self):
         assert self.family in FAMILIES, self.family

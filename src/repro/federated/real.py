@@ -107,6 +107,7 @@ class RealLearner:
         with spans.span("client.to_device", update=update,
                         copies=(base, data, mask) if stale else (data, mask)):
             deltas, _ = update_fn(base, data, mask)
+        spans.count("client.programs", 1)
         if self.fed.compression == "int8":
             deltas = aggregation.compress_roundtrip(
                 deltas, block=self.fed.quant_block)

@@ -6,6 +6,8 @@ VLM:    internvl2-2b (precomputed patch embeddings prepended — frontend stub)
 
 Pre-norm RMSNorm blocks, RoPE GQA attention (full or sliding-window),
 SwiGLU FFN or capacity-based top-k MoE. Layer stack runs under lax.scan.
+The training forward names its parts (``attention``, ``ffn``, ``lm_head``)
+with ``jax.named_scope``, so a device trace's op metadata can split them.
 """
 from __future__ import annotations
 
@@ -48,34 +50,46 @@ class DecoderLM:
 
     # ---------------------------------------------------------------- init
     def init(self, rng, dtype=jnp.float32) -> Tuple[cm.Params, cm.Axes]:
+        """Every normally drawn weight takes N(0, std**2) where the config
+        sets ``initializer_range``; otherwise the fixed scales of ``embed``
+        and ``wo`` and ``ParamBuilder``'s default for the rest."""
         cfg = self.cfg
         b = cm.ParamBuilder(rng, dtype)
         d, hd = cfg.d_model, cfg.resolved_head_dim
         H, Hkv, L = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
+        std = cfg.initializer_range
         b.param("embed", (cfg.vocab_size, d), ("vocab", "embed"),
-                scale=1.0 / math.sqrt(d))
+                scale=1.0 / math.sqrt(d) if std is None else std)
         if not cfg.tie_embeddings:
-            b.param("unembed", (d, cfg.vocab_size), ("embed", "vocab"))
+            b.param("unembed", (d, cfg.vocab_size), ("embed", "vocab"),
+                    scale=std)
         b.param("final_norm", (d,), ("embed",), init="ones")
         # stacked per-layer params
         b.param("blocks/attn_norm", (L, d), ("layers", "embed"), init="ones")
-        b.param("blocks/wq", (L, d, H, hd), ("layers", "embed", "heads", "head_dim"))
-        b.param("blocks/wk", (L, d, Hkv, hd), ("layers", "embed", "kv_heads", "head_dim"))
-        b.param("blocks/wv", (L, d, Hkv, hd), ("layers", "embed", "kv_heads", "head_dim"))
+        b.param("blocks/wq", (L, d, H, hd), ("layers", "embed", "heads", "head_dim"),
+                scale=std)
+        b.param("blocks/wk", (L, d, Hkv, hd), ("layers", "embed", "kv_heads", "head_dim"),
+                scale=std)
+        b.param("blocks/wv", (L, d, Hkv, hd), ("layers", "embed", "kv_heads", "head_dim"),
+                scale=std)
         b.param("blocks/wo", (L, H, hd, d), ("layers", "heads", "head_dim", "embed"),
-                scale=1.0 / math.sqrt(H * hd))
+                scale=1.0 / math.sqrt(H * hd) if std is None else std)
         b.param("blocks/ffn_norm", (L, d), ("layers", "embed"), init="ones")
         if self.is_moe:
             E, f = cfg.moe.num_experts, cfg.d_ff
-            b.param("blocks/router", (L, d, E), ("layers", "embed", "experts"))
-            b.param("blocks/w_gate", (L, E, d, f), ("layers", "experts", "embed", "ffn"))
-            b.param("blocks/w_up", (L, E, d, f), ("layers", "experts", "embed", "ffn"))
-            b.param("blocks/w_down", (L, E, f, d), ("layers", "experts", "ffn", "embed"))
+            b.param("blocks/router", (L, d, E), ("layers", "embed", "experts"),
+                    scale=std)
+            b.param("blocks/w_gate", (L, E, d, f), ("layers", "experts", "embed", "ffn"),
+                    scale=std)
+            b.param("blocks/w_up", (L, E, d, f), ("layers", "experts", "embed", "ffn"),
+                    scale=std)
+            b.param("blocks/w_down", (L, E, f, d), ("layers", "experts", "ffn", "embed"),
+                    scale=std)
         else:
             f = cfg.d_ff
-            b.param("blocks/w_gate", (L, d, f), ("layers", "embed", "ffn"))
-            b.param("blocks/w_up", (L, d, f), ("layers", "embed", "ffn"))
-            b.param("blocks/w_down", (L, f, d), ("layers", "ffn", "embed"))
+            b.param("blocks/w_gate", (L, d, f), ("layers", "embed", "ffn"), scale=std)
+            b.param("blocks/w_up", (L, d, f), ("layers", "embed", "ffn"), scale=std)
+            b.param("blocks/w_down", (L, f, d), ("layers", "ffn", "embed"), scale=std)
         return b.build()
 
     # ------------------------------------------------------------- forward
@@ -84,28 +98,32 @@ class DecoderLM:
         """One block on (B, S, d). Returns (x_out, k, v) (k/v for cache)."""
         cfg = self.cfg
         B, S, d = x.shape
-        h = cm.rms_norm(x, lp["attn_norm"])
-        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-        pos = positions_offset + jnp.arange(S)
-        cos, sin = cm.rope_angles(pos, cfg.resolved_head_dim, cfg.rope_theta)
-        q = cm.apply_rope(q, cos, sin)
-        k = cm.apply_rope(k, cos, sin)
-        attn = cm.flash_attention(q, k, v, causal=True,
-                                  window=cfg.sliding_window,
-                                  block_q=min(512, S), block_kv=min(512, S))
-        x = x + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
+        eps = cfg.rms_norm_eps
+        with jax.named_scope("attention"):
+            h = cm.rms_norm(x, lp["attn_norm"], eps)
+            q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
+            k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+            v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+            pos = positions_offset + jnp.arange(S)
+            cos, sin = cm.rope_angles(pos, cfg.resolved_head_dim,
+                                      cfg.rope_theta)
+            q = cm.apply_rope(q, cos, sin)
+            k = cm.apply_rope(k, cos, sin)
+            attn = cm.flash_attention(q, k, v, causal=True,
+                                      window=cfg.sliding_window,
+                                      block_q=min(512, S),
+                                      block_kv=min(512, S))
+            x = x + jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
 
-        h = cm.rms_norm(x, lp["ffn_norm"])
-        if self.is_moe:
-            out, aux = cm.moe_block(
-                h.reshape(B * S, d), lp["router"], lp["w_gate"], lp["w_up"],
-                lp["w_down"], top_k=cfg.moe.top_k,
-                capacity_factor=self.capacity_factor)
-            x = x + out.reshape(B, S, d)
-            return x, (k, v), aux
-        x = x + cm.swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+        with jax.named_scope("ffn"):
+            h = cm.rms_norm(x, lp["ffn_norm"], eps)
+            if self.is_moe:
+                out, aux = cm.moe_block(
+                    h.reshape(B * S, d), lp["router"], lp["w_gate"],
+                    lp["w_up"], lp["w_down"], top_k=cfg.moe.top_k,
+                    capacity_factor=self.capacity_factor)
+                return x + out.reshape(B, S, d), (k, v), aux
+            x = x + cm.swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
         return x, (k, v), jnp.zeros((), jnp.float32)
 
     def _stack(self, params: cm.Params, x: jnp.ndarray,
@@ -134,9 +152,11 @@ class DecoderLM:
         return x
 
     def logits(self, params, x):
-        x = cm.rms_norm(x, params["final_norm"])
-        w = params["embed"].T if self.cfg.tie_embeddings else params["unembed"]
-        return jnp.einsum("bsd,dv->bsv", x, w)
+        with jax.named_scope("lm_head"):
+            x = cm.rms_norm(x, params["final_norm"], self.cfg.rms_norm_eps)
+            w = params["embed"].T if self.cfg.tie_embeddings \
+                else params["unembed"]
+            return jnp.einsum("bsd,dv->bsv", x, w)
 
     # ----------------------------------------------------------- train api
     def loss(self, params: cm.Params, batch: Dict[str, jnp.ndarray]
@@ -145,9 +165,12 @@ class DecoderLM:
         x = self._embed(params, tokens, batch.get("frontend"))
         x, _, aux = self._stack(params, x, collect_kv=False)
         nf = self.cfg.num_frontend_tokens if "frontend" in batch else 0
-        x = cm.rms_norm(x[:, nf:], params["final_norm"])
-        w = params["embed"].T if self.cfg.tie_embeddings else params["unembed"]
-        loss = cm.lm_loss(x, w, batch["labels"], batch.get("mask", None))
+        with jax.named_scope("lm_head"):
+            x = cm.rms_norm(x[:, nf:], params["final_norm"],
+                            self.cfg.rms_norm_eps)
+            w = params["embed"].T if self.cfg.tie_embeddings \
+                else params["unembed"]
+            loss = cm.lm_loss(x, w, batch["labels"], batch.get("mask", None))
         total = loss
         if self.is_moe:
             total = loss + self.cfg.moe.router_aux_weight * aux
@@ -210,7 +233,7 @@ class DecoderLM:
 
         def body(x, per_layer):
             lp, kc, vc = per_layer
-            h = cm.rms_norm(x, lp["attn_norm"])
+            h = cm.rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
             q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
             k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
             v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
@@ -229,7 +252,7 @@ class DecoderLM:
                 vc = _sh.constrain_batch(vc)
                 attn = cm.decode_attention(q[:, 0], kc, vc, valid)
             x = x + jnp.einsum("bhk,hkd->bd", attn, lp["wo"])[:, None, :]
-            h = cm.rms_norm(x, lp["ffn_norm"])
+            h = cm.rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps)
             if self.serve_replicated_ffn:
                 h = _sh.constrain_replicated(h)
             if self.is_moe:
@@ -257,7 +280,7 @@ class DecoderLM:
 
         def body(x, per_layer):
             lp, kc, vc, ks_, vs_ = per_layer
-            h = cm.rms_norm(x, lp["attn_norm"])
+            h = cm.rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
             q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
             k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
             v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
@@ -269,7 +292,7 @@ class DecoderLM:
             attn, kc, vc, ks_, vs_ = cm.flash_decode_attention_q8(
                 q[:, 0], kc, vc, ks_, vs_, k[:, 0], v[:, 0], write_idx, valid)
             x = x + jnp.einsum("bhk,hkd->bd", attn, lp["wo"])[:, None, :]
-            h = cm.rms_norm(x, lp["ffn_norm"])
+            h = cm.rms_norm(x, lp["ffn_norm"], cfg.rms_norm_eps)
             if self.serve_replicated_ffn:
                 h = _sh.constrain_replicated(h)
             if self.is_moe:

@@ -187,8 +187,25 @@ def test_rows_real_counts_the_datasets_own_mask_rows(mode):
                for b in lr.dataset.client_batches(c, BATCH, 1)[:STEPS])
     got = spans.snapshot()["counters"]
     assert got == {"client.rows_real": want,
-                   "client.rows_computed": len(SYNC_IDS) * STEPS * BATCH}
+                   "client.rows_computed": len(SYNC_IDS) * STEPS * BATCH,
+                   "client.programs": 1 if mode == "sync" else len(SYNC_IDS)}
     assert 0 < want <= got["client.rows_computed"]
+
+
+@pytest.mark.parametrize("mode,run,calls", [("sync", _sync_round, 1),
+                                            ("async", _fedbuff, 3)])
+def test_client_programs_counts_one_per_client_program_call(mode, run,
+                                                            calls):
+    """One vmapped cohort call per sync round, one call per FedBuff
+    client; rows computed over programs is the cohort a program ran."""
+    lr = _learner(mode)
+    spans.enable()
+    run(lr)
+    got = spans.snapshot()["counters"]
+    assert got["client.programs"] == calls
+    per_program = got["client.rows_computed"] // got["client.programs"]
+    assert per_program == STEPS * BATCH * (len(SYNC_IDS) if mode == "sync"
+                                           else 1)
 
 
 def test_count_reset_and_dump_round_trip():

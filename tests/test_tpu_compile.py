@@ -83,3 +83,29 @@ def test_cohort_client_step_fits_one_chip(one_chip):
              + m.argument_size_in_bytes)
     assert m.output_size_in_bytes >= K_ * PARAMS * 4     # the K f32 deltas
     assert total < HBM_BYTES, f"{total / 2**30:.2f} GiB"
+
+
+def test_smollm_cohort_of_four_fits_one_chip(one_chip):
+    """The smollm-sync cell's largest cohort program: SmolLM-135M at its
+    published widths, depth and init, K=4 clients x 8 steps x batch 8 at
+    seq_len 64, beside the params and FedAdam's two moments."""
+    K_, steps, batch, seq = 4, 8, 8, 64
+    cfg = get_config("smollm-135m")
+    assert cfg.initializer_range == 0.02 and cfg.rms_norm_eps == 1e-5
+    model = get_model(cfg)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0))[0])
+    n = sum(int(np.prod(p.shape)) for p in params.values())
+    assert n == 134_515_008
+    update = jax.jit(jax.vmap(make_client_update(model.loss, 0.1),
+                              in_axes=(None, 0, 0)))
+    tok = _sds((K_, steps, batch, seq), jnp.int32, one_chip)
+    compiled = update.lower(
+        {k: _sds(p.shape, p.dtype, one_chip) for k, p in params.items()},
+        {"tokens": tok, "labels": tok,
+         "mask": _sds((K_, steps, batch, seq - 1), jnp.float32, one_chip)},
+        _sds((K_, steps), jnp.float32, one_chip)).compile()
+    m = compiled.memory_analysis()
+    total = (m.temp_size_in_bytes + m.output_size_in_bytes
+             + m.argument_size_in_bytes)
+    assert m.output_size_in_bytes >= K_ * n * 4           # the K f32 deltas
+    assert total + 2 * n * 4 < HBM_BYTES, f"{total / 2**30:.2f} GiB"
