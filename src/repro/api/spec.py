@@ -93,7 +93,7 @@ class ExperimentSpec:
     environment: Environment = field(default_factory=Environment)
     learner: str = "surrogate"          # "surrogate" | "real"
     seq_len: int = 64
-    max_client_steps: int = 8           # real learner scan length
+    max_client_steps: int = 8           # real learner: steps a client pads to
 
     def __post_init__(self):
         assert self.learner in LEARNERS, self.learner

@@ -1,7 +1,9 @@
 """Real JAX learner: actual federated training of any model-zoo config.
 
 Holds server params + FedAdam state, compiles the client local-SGD step
-once (ragged client datasets are padded into a fixed scan length), and —
+once (ragged client datasets are padded into one fixed shape of
+``max_client_steps`` steps; the loop stops at the cohort's last real
+step, so the shape stays fixed and the trip count does not), and —
 for FedBuff — keeps a ring of recent param versions so stale clients
 really do train against the model they were sent (true staleness, not an
 approximation). Deltas optionally round-trip the int8 wire codec.
@@ -33,7 +35,8 @@ from repro import spans
 from repro.configs.base import FederatedConfig, ModelConfig, RunConfig
 from repro.data.synthetic import FederatedDataset
 from repro.federated import aggregation
-from repro.federated.client import make_client_update, stack_batches
+from repro.federated.client import (make_client_update, make_cohort_update,
+                                    stack_batches, trip_count)
 from repro.models import get_model
 from repro.optim import server_optimizer
 
@@ -57,8 +60,8 @@ class RealLearner:
                                     eps=fed.adam_eps)
         self.opt_state = self.opt.init(self.params)
         self._client_update = make_client_update(self.model.loss, fed.client_lr)
-        self._vmapped_update = jax.jit(jax.vmap(
-            self._client_update, in_axes=(None, 0, 0)))
+        self._vmapped_update = make_cohort_update(self.model.loss,
+                                                  fed.client_lr)
         self.version = 0
         self._history: List[Tuple[int, Dict[str, np.ndarray]]] = []
         self._push_history()
@@ -155,7 +158,7 @@ class RealLearner:
             cohort = {k: np.stack([st[k] for st, _ in packed])
                       for k in packed[0][0]}
             cmask = np.stack([m for _, m in packed])
-        _count_rows(cohort["mask"])
+        _count_rows(cohort["mask"], cmask)
         n_ex = [float(min(len(b), self.max_steps) * self.fed.client_batch_size)
                 for b in batches]
         # a cohort kept from a call that no apply followed would stay live
@@ -175,7 +178,7 @@ class RealLearner:
             client_id, self.fed.client_batch_size, self.fed.local_epochs)
         with spans.span("client.pack", update=feeds):
             stacked, mask = stack_batches(batches, self.max_steps)
-        _count_rows(stacked["mask"])
+        _count_rows(stacked["mask"], mask)
         n_ex = min(len(batches), self.max_steps) * self.fed.client_batch_size
         tree, out = self._train(self._client_update, version, stacked, mask,
                                 feeds)
@@ -276,10 +279,15 @@ def _gather_rows(blocks: List[Dict], singles: List[Dict], order):
     return {k: leaf(k) for k in (singles or blocks)[0]}
 
 
-def _count_rows(mask: np.ndarray) -> None:
-    """Rows the client update computes (steps x batch per client) and the
-    rows among them that hold data: a data row's mask is 1 at its first
-    position, a padding row's is 0."""
+def _count_rows(mask: np.ndarray, step_mask: np.ndarray) -> None:
+    """Rows the client update computes (clients x the steps its loop runs
+    x batch), the rows of padded steps past the loop's end that it skips,
+    and the rows it computes that hold data: a data row's mask is 1 at its
+    first position, a padding row's is 0."""
     if spans.enabled():
+        steps = step_mask.shape[-1]
+        clients, batch = step_mask.size // steps, mask.shape[-2]
+        n = int(trip_count(step_mask))
         spans.count("client.rows_real", int(mask[..., 0].sum()))
-        spans.count("client.rows_computed", mask[..., 0].size)
+        spans.count("client.rows_computed", clients * n * batch)
+        spans.count("client.rows_skipped", clients * (steps - n) * batch)

@@ -40,13 +40,15 @@ import jax
 SPANS = ("client.pack", "client.to_device", "client.wait", "client.to_host",
          "server.to_device", "server.update", "server.history_to_host",
          "server.eval")
-# client.rows_*: rows of the client update that hold data, and all it
-# computes; client.programs: calls of a client program (one per vmapped
+# client.rows_*: rows of the client update that hold data, all it
+# computes, and those of padded steps past the loop's end it skips;
+# client.programs: calls of a client program (one per vmapped
 # cohort, one per FedBuff client); server.deltas_*: client deltas the
 # server step took from the device copies the client programs made, and
 # those it uploaded
-COUNTERS = ("client.rows_real", "client.rows_computed", "client.programs",
-            "server.deltas_resident", "server.deltas_uploaded")
+COUNTERS = ("client.rows_real", "client.rows_computed", "client.rows_skipped",
+            "client.programs", "server.deltas_resident",
+            "server.deltas_uploaded")
 
 _on = False
 _records: List[Dict[str, Any]] = []

@@ -173,23 +173,42 @@ def test_a_stale_base_is_counted_once_per_stale_client_only():
     assert got["server.history_to_host"]["bytes"] == 2 * pb
 
 
+def _steps(lr: RealLearner, cid: int) -> int:
+    """The local steps that hold data for client ``cid``: its own batches,
+    up to ``STEPS``."""
+    return min(len(lr.dataset.client_batches(cid, BATCH, 1)), STEPS)
+
+
 @pytest.mark.parametrize("mode", ["sync", "async"])
 def test_rows_real_counts_the_datasets_own_mask_rows(mode):
+    """Rows computed are clients x the steps the loop runs x batch: in
+    sync the cohort runs to its longest client's last real step, in
+    FedBuff each client to its own; the padded steps past it are the
+    rows skipped."""
     lr = _learner(mode)
     spans.enable()
     if mode == "sync":
         lr.client_deltas(SYNC_IDS)
+        runs = [len(SYNC_IDS) * max(_steps(lr, c) for c in SYNC_IDS)]
     else:
         for c in SYNC_IDS:
             lr.client_delta(c)
+        runs = [_steps(lr, c) for c in SYNC_IDS]
     want = sum(int(b["mask"][:, 0].sum())
                for c in SYNC_IDS
                for b in lr.dataset.client_batches(c, BATCH, 1)[:STEPS])
     got = spans.snapshot()["counters"]
     assert got == {"client.rows_real": want,
-                   "client.rows_computed": len(SYNC_IDS) * STEPS * BATCH,
+                   "client.rows_computed": sum(runs) * BATCH,
+                   "client.rows_skipped": (len(SYNC_IDS) * STEPS
+                                           - sum(runs)) * BATCH,
                    "client.programs": 1 if mode == "sync" else len(SYNC_IDS)}
     assert 0 < want <= got["client.rows_computed"]
+    # client 3 holds one batch: FedBuff runs it one step and skips one,
+    # while the cohort runs it to its longer clients' second step
+    assert _steps(lr, 3) == 1 < STEPS == max(_steps(lr, c)
+                                             for c in SYNC_IDS)
+    assert got["client.rows_skipped"] == (0 if mode == "sync" else BATCH)
 
 
 @pytest.mark.parametrize("mode,run,calls", [("sync", _sync_round, 1),
@@ -197,15 +216,21 @@ def test_rows_real_counts_the_datasets_own_mask_rows(mode):
 def test_client_programs_counts_one_per_client_program_call(mode, run,
                                                             calls):
     """One vmapped cohort call per sync round, one call per FedBuff
-    client; rows computed over programs is the cohort a program ran."""
+    client; each program computes its clients x the steps its loop runs
+    x batch, and skips the rest of the ``STEPS`` x batch."""
     lr = _learner(mode)
     spans.enable()
     run(lr)
     got = spans.snapshot()["counters"]
     assert got["client.programs"] == calls
-    per_program = got["client.rows_computed"] // got["client.programs"]
-    assert per_program == STEPS * BATCH * (len(SYNC_IDS) if mode == "sync"
-                                           else 1)
+    # _sync_round trains SYNC_IDS as one cohort; _fedbuff trains clients
+    # 3, 5 and 7, in that order, one program each
+    programs = ([(len(SYNC_IDS), max(_steps(lr, c) for c in SYNC_IDS))]
+                if mode == "sync" else [(1, _steps(lr, c)) for c in (3, 5, 7)])
+    assert got["client.rows_computed"] == sum(k * n * BATCH
+                                              for k, n in programs)
+    assert got["client.rows_skipped"] == sum(k * (STEPS - n) * BATCH
+                                             for k, n in programs)
 
 
 def test_count_reset_and_dump_round_trip():
