@@ -1,4 +1,9 @@
-"""Shared benchmark helpers: parallel grid runner + CSV emission.
+"""Shared helpers of the paper-figure scripts: parallel grid runner + CSV
+emission.
+
+The ``fig*`` and ``table_*`` scripts reproduce the paper's tables and
+figures with the surrogate engine; they are not speed measurements (the
+repo's one benchmark is ``benchmarks/chip``, run on the chip).
 
 Sweeps go through ``repro.api.sweep`` — build the specs with
 ``make_spec``, run them all with ``run_points(points, workers=N)``, and

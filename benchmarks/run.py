@@ -13,9 +13,8 @@ import time
 
 from benchmarks import (fig1_design_space, fig5_sync_vs_async, fig6_fixed_time,
                         fig7_concurrency, fig8_fig9_regression,
-                        fig10_async_design_space, roofline_report,
-                        table_component_breakdown, table_compression,
-                        table_recipe_spread)
+                        fig10_async_design_space, table_component_breakdown,
+                        table_compression, table_recipe_spread)
 from benchmarks.common import write_csv
 
 HARNESSES = [
@@ -32,7 +31,6 @@ HARNESSES = [
      "sync_client_compute"),
     ("table_compression_int8", table_compression, "measured_reduction"),
     ("table_recipe_spread", table_recipe_spread, "spread_max_over_min"),
-    ("roofline_dryrun", roofline_report, "n_pairs_ok"),
 ]
 
 
@@ -58,7 +56,7 @@ def main() -> None:
             all_derived[name] = derived
         except Exception as e:  # keep the suite going
             print(f"{name},{(time.time()-t0)*1e6:.0f},ERROR:{e!r}")
-    # full derived dump for EXPERIMENTS.md
+    # every harness's derived metrics in one file
     import json
     with open("results/benchmark_derived.json", "w") as f:
         json.dump(all_derived, f, indent=1, default=str)

@@ -1,7 +1,6 @@
 #!/usr/bin/env bash
 # CI smoke: tier-1 test suite + ExperimentSpec JSON dry-runs end-to-end
-# + the crash-inject/resume contract + the simulation-engine runtime
-# benchmark.
+# + the crash-inject/resume contract.
 #
 #   bash scripts/smoke.sh            # from the repo root
 #
@@ -20,32 +19,15 @@
 # run it uninterrupted, run it again with checkpointing while the crash
 # injector kills the run mid-way, resume from the checkpoint, and assert
 # the resumed summary is bit-identical to the uninterrupted one.
-#
-# Step 4 runs the quick fig5-style engine benchmark (columnar vs scalar),
-# refreshes BENCH_runtime.json + BENCH_history.json, and FAILS if the
-# columnar engine's quick sessions/sec regressed more than 2x against the
-# recorded baseline — overall or in any mode (sync, async and
-# carbon-aware are each gated separately, as are the fault_stress,
-# churn_stress and carbon_aware_stress points — the last one keeps the
-# precompiled schedule-segment screening honest with both diurnal grids
-# live). The bench also runs the population_stress streaming-telemetry
-# point (gated on peak RSS, streaming parity and throughput) and the
-# checkpoint_overhead point (checkpointing every 50 windows must cost
-# < 1.1x the plain wall).
-#
-# Step 5 runs the quick design-space sweep benchmark (lane-batched packs
-# vs sweep(workers=1) serial; summaries must match seed-for-seed) and
-# FAILS on a >2x lane-throughput regression against the recorded
-# baseline under BENCH_runtime.json's "sweep" key.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-echo "== smoke 1/5: tier-1 test suite =="
+echo "== smoke 1/3: tier-1 test suite =="
 python -m pytest -x -q
 
-echo "== smoke 2/5: ExperimentSpec JSON dry-runs (with round-trip check) =="
+echo "== smoke 2/3: ExperimentSpec JSON dry-runs (with round-trip check) =="
 python -m repro.api examples/specs/charlm_sync_small.json \
     --roundtrip-check --quiet
 python -m repro.api examples/specs/charlm_async_small.json \
@@ -59,7 +41,7 @@ python -m repro.api examples/specs/charlm_faulty_bursts.json \
 python -m repro.api examples/specs/charlm_avail_churn.json \
     --roundtrip-check --quiet
 
-echo "== smoke 3/5: crash-inject -> resume -> bit-identical summary =="
+echo "== smoke 3/3: crash-inject -> resume -> bit-identical summary =="
 python - <<'PY'
 import os
 import tempfile
@@ -88,11 +70,5 @@ print(f"resume contract OK: killed at round 60, resumed run matches "
       f"uninterrupted run exactly ({res.rounds} rounds, "
       f"{res.log.n_sessions} sessions)")
 PY
-
-echo "== smoke 4/5: runtime benchmark (quick, per-mode 2x regression gate) =="
-python benchmarks/bench_runtime.py --quick --check
-
-echo "== smoke 5/5: sweep benchmark (quick, lane 2x regression gate) =="
-python benchmarks/bench_sweep.py --quick --check
 
 echo "smoke OK"

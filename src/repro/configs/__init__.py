@@ -1,6 +1,5 @@
 from repro.configs.base import (
-    FederatedConfig, ModelConfig, MoEConfig, RunConfig, ShapeConfig,
-    INPUT_SHAPES, model_config_from_dict, model_config_to_dict,
-    normalize_model_kwargs, reduced,
+    FederatedConfig, ModelConfig, MoEConfig, RunConfig, model_config_from_dict,
+    model_config_to_dict, normalize_model_kwargs, reduced,
 )
-from repro.configs.registry import ALL_ARCHS, ASSIGNED_ARCHS, all_configs, get_config
+from repro.configs.registry import ALL_ARCHS, all_configs, get_config

@@ -116,29 +116,6 @@ class ModelConfig:
         """~6*N(active) per token (fwd+bwd)."""
         return 6.0 * self.active_param_count()
 
-    def decode_flops_per_token(self) -> float:
-        return 2.0 * self.active_param_count()
-
-
-# ---------------------------------------------------------------------------
-# Input shapes (assigned)
-# ---------------------------------------------------------------------------
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-INPUT_SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
-}
-
 
 # ---------------------------------------------------------------------------
 # Federated / green configs (the paper's Table 1 hyperparameter space)

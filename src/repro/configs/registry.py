@@ -20,7 +20,6 @@ _ARCH_MODULES = {
     "paper-charlm": "paper_charlm",
 }
 
-ASSIGNED_ARCHS = tuple(k for k in _ARCH_MODULES if k != "paper-charlm")
 ALL_ARCHS = tuple(_ARCH_MODULES)
 
 

@@ -4,13 +4,10 @@ The production strategies in ``repro.federated.runtime`` are vectorized
 end-to-end (``plan_batch``/``resolve_batch``/``SessionBatch``). This module
 preserves the original per-session Python loops, driven by the sampler's
 ``plan_scalar``/``resolve_scalar`` and the estimator's ``estimate_scalar``,
-for two purposes only:
-
-* seed-for-seed equivalence tests (``tests/test_columnar.py``) prove the
-  columnar sync AND async engines reproduce this loop's TaskLog stats and
-  CarbonBreakdown;
-* ``benchmarks/bench_runtime.py`` measures sessions/sec against it, so the
-  vectorization speedup is tracked across PRs.
+for one purpose only: seed-for-seed equivalence tests
+(``tests/test_columnar.py``, and the fault, availability and
+time-varying-carbon suites) prove the columnar sync AND async engines
+reproduce this loop's TaskLog stats and CarbonBreakdown.
 
 The async loop here still pops a heap one session at a time, but it is
 keyed the same way as the vectorized window merge: heap order is

@@ -1,6 +1,6 @@
 """Pallas TPU flash attention with causal + sliding-window masking.
 
-TPU adaptation (DESIGN.md §3): classic FlashAttention online-softmax, tiled
+TPU adaptation: classic FlashAttention online-softmax, tiled
 for VMEM — q tile (BLOCK_Q, D) and kv tiles (BLOCK_KV, D) with D padded to
 lane width 128 and block sizes multiples of the MXU dim. Grid is
 (batch*kv_head*group, n_q, n_kv); the LAST grid axis is sequential on TPU,

@@ -1,3 +1,1 @@
-from repro.models.registry import (attention_flops, get_model, param_count,
-                                   param_shapes_and_axes, step_bytes_min,
-                                   step_flops)
+from repro.models.registry import get_model, param_count, param_shapes_and_axes

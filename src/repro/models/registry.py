@@ -1,14 +1,11 @@
-"""Model construction + analytic parameter/FLOP accounting.
+"""Model construction + analytic parameter accounting.
 
-Accounting feeds (a) the Green-FL energy model (client FLOPs -> duration ->
-energy) and (b) the roofline's MODEL_FLOPS and scan-undercount corrections
-(layer stacks / attention block schedules / time recurrences run under
-``lax.scan``, whose body XLA's cost model counts once — see DESIGN.md §5).
+The parameter counts feed the Green-FL energy model (client FLOPs ->
+duration -> energy) through ``ModelConfig.param_count``.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -65,87 +62,3 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
             n *= cfg.moe.top_k / cfg.moe.num_experts
         total += n
     return int(total)
-
-
-# ---------------------------------------------------------------------------
-# Analytic FLOPs (exact-schedule attention / recurrence corrections)
-# ---------------------------------------------------------------------------
-
-def _attn_pairs(S: int, window: int) -> float:
-    """Number of (q, kv) attended pairs under causal (banded) masking."""
-    if not window or window >= S:
-        return S * (S + 1) / 2.0
-    w = window
-    return w * S - w * (w - 1) / 2.0 - w  # ramp-up + band (approx exact)
-
-
-def attention_flops(cfg: ModelConfig, batch: int, seq: int,
-                    n_attn_layers: Optional[int] = None,
-                    window: Optional[int] = None) -> float:
-    """Forward FLOPs of score+value matmuls across attention layers."""
-    if cfg.family == SSM:
-        # WKV state update+readout: ~4 mults per (token, head, hd, hd)
-        hd = cfg.resolved_head_dim
-        H = cfg.d_model // hd
-        return 4.0 * batch * seq * H * hd * hd * cfg.num_layers
-    H, hd = cfg.num_heads, cfg.resolved_head_dim
-    w = cfg.sliding_window if window is None else window
-    if n_attn_layers is None:
-        if cfg.family == HYBRID:
-            n_attn_layers = cfg.num_layers // 3
-            # plus RG-LRU elementwise recurrence (~6 flops/elem)
-            extra = 6.0 * batch * seq * (cfg.lru_width or cfg.d_model) \
-                * (cfg.num_layers - n_attn_layers)
-        else:
-            n_attn_layers = cfg.num_layers
-            extra = 0.0
-    else:
-        extra = 0.0
-    pairs = _attn_pairs(seq, w)
-    per_layer = 4.0 * batch * pairs * H * hd          # qk^T + pv, 2 flops/mac
-    total = per_layer * n_attn_layers + extra
-    if cfg.is_encoder_decoder:
-        T = cfg.num_frontend_tokens
-        enc = 4.0 * batch * T * T * H * hd * cfg.encoder_layers
-        cross = 4.0 * batch * seq * T * H * hd * cfg.num_layers
-        total = total + enc + cross
-    return total
-
-
-def step_flops(cfg: ModelConfig, batch: int, seq: int, kind: str) -> float:
-    """Analytic FLOPs of one train/prefill/decode step (whole step)."""
-    n_active = param_count(cfg, active_only=True)
-    if kind == "train":
-        matmul = 6.0 * n_active * batch * seq
-        attn = 3.0 * attention_flops(cfg, batch, seq)   # fwd + 2x bwd
-    elif kind == "prefill":
-        matmul = 2.0 * n_active * batch * seq
-        attn = attention_flops(cfg, batch, seq)
-    elif kind == "decode":
-        matmul = 2.0 * n_active * batch
-        if cfg.family == SSM:
-            attn = attention_flops(cfg, batch, 1)
-        else:
-            # one query against the full cache
-            attn = 4.0 * batch * min(seq, cfg.sliding_window or seq) \
-                * cfg.num_heads * cfg.resolved_head_dim * cfg.num_layers
-    else:
-        raise ValueError(kind)
-    return matmul + attn
-
-
-def step_bytes_min(cfg: ModelConfig, batch: int, seq: int, kind: str) -> float:
-    """Lower-bound HBM traffic (params once + activations/cache once, bf16)."""
-    n = param_count(cfg)
-    if kind == "train":
-        # params + grads + adam m,v (f32) + activations
-        return 2.0 * n * 4 + batch * seq * cfg.d_model * 2 * cfg.num_layers
-    if kind == "prefill":
-        return 2.0 * n + batch * seq * cfg.d_model * 2 * cfg.num_layers
-    # decode: params + full KV cache read
-    cache = 2 * batch * min(seq, cfg.sliding_window or seq) * \
-        max(cfg.num_kv_heads, 1) * max(cfg.resolved_head_dim, 1) * 2 * cfg.num_layers
-    if cfg.family == SSM:
-        hd = cfg.resolved_head_dim
-        cache = batch * (cfg.d_model // hd) * hd * hd * 4 * cfg.num_layers
-    return 2.0 * n + cache

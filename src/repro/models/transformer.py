@@ -33,9 +33,9 @@ class DecoderLM:
         self.decode_window = decode_window or cfg.sliding_window
         self.is_moe = cfg.moe is not None
         self.remat = remat
-        # GShard-style expert capacity for train/prefill (documented
-        # deviation from Mixtral's dropless routing — DESIGN.md §4);
-        # decode runs dropless (capacity = tokens x top_k).
+        # GShard-style expert capacity for train/prefill: a deviation
+        # from Mixtral's dropless routing that keeps expert buffers a
+        # static shape; decode runs dropless (capacity = tokens x top_k).
         self.capacity_factor = 1.25
         # §Perf H1.3: replicate (tiny) decode activations across the data
         # axis for the FFN/unembed segment so 2D-resident weights are

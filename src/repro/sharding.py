@@ -21,7 +21,6 @@ from __future__ import annotations
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 MeshAxes = Union[str, Tuple[str, ...], None]
@@ -42,22 +41,6 @@ DEFAULT_RULES: Dict[str, Sequence[MeshAxes]] = {
     "layers": (),
     "head_dim": (),
     "head_dim2": (),
-}
-
-# rules for replicated-parameter (pure data-parallel / vmap-client) mode
-DP_RULES: Dict[str, Sequence[MeshAxes]] = {
-    **{k: () for k in DEFAULT_RULES},
-    "batch": (("pod", "data"), "data"),
-    "clients": (("pod", "data"), "data"),
-}
-
-# cross-device simulation (vmap-client) rules: params TP over "model" but NO
-# FSDP — each data-axis slice carries whole per-client param deltas, the
-# faithful small-model cross-device regime (smollm / charlm).
-XDEVICE_RULES: Dict[str, Sequence[MeshAxes]] = {
-    **DEFAULT_RULES,
-    "embed": (),
-    "clients": (("pod", "data"), "data"),
 }
 
 # Serving (decode) rules: weights stay RESIDENT — 2D-sharded over
@@ -131,18 +114,6 @@ def spec_for(logical: Sequence[Optional[str]], shape: Sequence[int],
     return P(*out)
 
 
-def tree_specs(axes_tree: Dict[str, Tuple[Optional[str], ...]],
-               shapes: Dict[str, jax.ShapeDtypeStruct], mesh: Mesh,
-               rules=None) -> Dict[str, P]:
-    return {k: spec_for(axes_tree[k], shapes[k].shape, mesh, rules)
-            for k in axes_tree}
-
-
-def tree_shardings(axes_tree, shapes, mesh, rules=None):
-    return {k: NamedSharding(mesh, s)
-            for k, s in tree_specs(axes_tree, shapes, mesh, rules).items()}
-
-
 def batch_spec(mesh: Mesh, ndim: int, *, batch_dim: int = 0,
                shape: Optional[Sequence[int]] = None) -> P:
     """Shard the batch dim over ("pod","data") where divisible."""
@@ -207,7 +178,3 @@ def constrain_replicated(x):
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(m, P(*([None] * x.ndim))))
 
-
-def count_bytes(tree) -> int:
-    leaves = jax.tree.leaves(tree)
-    return int(sum(np.prod(l.shape) * l.dtype.itemsize for l in leaves))

@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
-see the single real CPU device; only launch/dryrun.py forces 512 devices."""
+see the single real CPU device."""
 import os
 
 # keep test threads polite on shared CI boxes

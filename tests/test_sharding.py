@@ -1,7 +1,7 @@
 """Sharding rules: divisibility fallbacks, serve vs train rules, batch spec.
 Uses a handful of forced host devices in a subprocess-free way: these tests
 only construct Meshes over the single real device via mesh abstractions, so
-we test spec RESOLUTION (pure logic), not lowering (covered by dryrun)."""
+we test spec RESOLUTION (pure logic), not lowering."""
 import numpy as np
 import pytest
 

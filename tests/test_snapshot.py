@@ -96,6 +96,22 @@ def test_killed_and_resumed_run_is_bit_exact(mode, telemetry, crash_at,
         _assert_same_columns(res.log.columns(), base.log.columns())
 
 
+@pytest.mark.parametrize("telemetry", ("full", "streaming"))
+@pytest.mark.parametrize("mode", _MODES)
+def test_checkpointing_does_not_perturb_the_run(mode, telemetry, tmp_path):
+    """Snapshots observe the loop, they never perturb it: a run that
+    checkpoints every few rounds and is never killed ends with the same
+    summary and session columns as the same spec run without them."""
+    spec = _spec(mode, env_idx=2, telemetry=telemetry)
+    plain = Experiment(spec).run()
+    path = str(tmp_path / "snap.npz")
+    ckpt = Experiment(spec).run(checkpoint_path=path,
+                                checkpoint_every_rounds=4)
+    assert ckpt.checkpoint_stats["saves"] > 0
+    assert ckpt.summary() == plain.summary()
+    _assert_same_columns(ckpt.log.columns(), plain.log.columns())
+
+
 def test_resume_keeps_checkpointing_to_the_same_file(monkeypatch,
                                                      tmp_path):
     """By default `Experiment.resume` continues the checkpoint cadence it
